@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import hashlib
 import json
+import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -191,7 +192,13 @@ class ElasticTrainer:
     def _step_jit(self):
         key = (len(self.pods), id(self.mesh))
         if key not in self._jitted:
-            self._jitted[key] = jax.jit(self.step_fn, donate_argnums=0)
+            # The new state keeps the mesh's shardings: the donated buffers
+            # are reused in place and the next step hits the same program.
+            self._jitted[key] = jax.jit(
+                self.step_fn,
+                donate_argnums=0,
+                out_shardings=(self._state_shardings, None),
+            )
         return self._jitted[key]
 
     # ------------------------------------------------------------------
@@ -229,6 +236,7 @@ class ElasticTrainer:
 
     # ------------------------------------------------------------------
     def save_checkpoint(self) -> None:
+        t0 = time.perf_counter()
         man = checkpoint.save(
             self.ecfg.checkpoint_dir,
             self.step,
@@ -239,6 +247,9 @@ class ElasticTrainer:
             json.dumps(man["files"], sort_keys=True).encode()
         ).hexdigest()[:16]
         self.controller.commit_checkpoint(self.step, digest)
+        self.events.append(
+            {"t": "checkpoint", "step": self.step, "seconds": time.perf_counter() - t0}
+        )
 
     def restore_latest(self) -> bool:
         man = checkpoint.latest_manifest(self.ecfg.checkpoint_dir)

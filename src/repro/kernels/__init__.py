@@ -5,8 +5,9 @@ kernel of its own; these kernels serve the *data plane* the control plane
 manages: flash attention (causal / sliding-window / softcap), flash-decode
 attention over long KV caches, and the Mamba-2 SSD intra-chunk block.
 
-Validated with interpret=True on CPU against the ref.py jnp oracles;
-compiled natively (interpret=False) on real TPUs.
+Validated in the Pallas interpreter on CPU against the ref.py jnp
+oracles; compiled natively on a TPU backend (kernels/backend.py), and
+compiled for a described v5e in tests/kernels/test_tpu_compile.py.
 """
 
 from . import ops, ref

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
+from .backend import resolve_interpret
 
 MASK = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -100,7 +100,7 @@ def decode_attention_bkh(
     window: Optional[int] = None,
     softcap: Optional[float] = None,
     block_k: int = 256,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     B, H, hd = q.shape
     K, S = k_cache.shape[1], k_cache.shape[2]
@@ -135,9 +135,9 @@ def decode_attention_bkh(
             pltpu.VMEM((q_per_kv, 128), jnp.float32),
             pltpu.VMEM((q_per_kv, 128), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lengths, qg, k_cache, v_cache)
     return out.reshape(B, H, hd)

@@ -1,10 +1,9 @@
 """Jit'd wrappers: the public kernel API used by the model layer.
 
-Each op accepts ``use_pallas`` / ``interpret`` switches: on real TPUs the
-Pallas path compiles natively (``interpret=False``); on this CPU container
-it executes in interpret mode (tests) or falls back to the jnp reference
-(dry-run lowering, where a python-interpreted kernel would be absurd to
-trace at 32k sequence length).
+Each op accepts ``use_pallas`` / ``interpret`` switches.  ``interpret``
+defaults to the backend (kernels/backend.py): on a TPU the Pallas path
+compiles natively, elsewhere it runs in the interpreter (tests).
+``use_pallas=False`` takes the jnp reference instead.
 """
 
 from __future__ import annotations
@@ -49,7 +48,7 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
@@ -89,7 +88,7 @@ def decode_attention(
     softcap: Optional[float] = None,
     block_k: int = 256,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     qt = q[:, 0]  # (B, H, hd)
     kt = k_cache.transpose(0, 2, 1, 3)  # (B, K, S, hd)
@@ -125,7 +124,7 @@ def ssd(
     *,
     chunk: int = 256,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Mirror of models.mamba2.ssd_chunked with the intra-chunk block on
     the Pallas kernel.  Returns (y (B,S,nh,hd), final_state (B,nh,hd,N))."""

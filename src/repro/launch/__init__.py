@@ -1,12 +1,12 @@
 """Launchers: production mesh, multi-pod dry-run, roofline analysis,
 elastic training and batched serving CLIs."""
 
-from .mesh import DCN_BW, HBM_BW, ICI_BW, PEAK_BF16_FLOPS, make_production_mesh
+from .mesh import PEAKS, PRODUCTION_DEVICE_KIND, ChipPeaks, make_production_mesh, peaks_for
 
 __all__ = [
-    "DCN_BW",
-    "HBM_BW",
-    "ICI_BW",
-    "PEAK_BF16_FLOPS",
+    "PEAKS",
+    "PRODUCTION_DEVICE_KIND",
+    "ChipPeaks",
     "make_production_mesh",
+    "peaks_for",
 ]

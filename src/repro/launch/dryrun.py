@@ -1,9 +1,12 @@
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# ^ MUST precede every other import (jax locks the device count on first
-# init).  Everything below is ordinary code.
+# ^ MUST precede every other import (jax locks the device count and the
+# platform on first init): the 512 fake devices are host CPUs, and a
+# machine with a TPU must not hand its chip to the dry run instead.
+# Everything below is ordinary code.
 
 """Multi-pod dry-run: lower + compile every (architecture x input-shape x
 mesh) cell against the production mesh, prove it fits
@@ -28,7 +31,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import SHAPES, get_config, normalize, shape_applicable
 from repro.coord.elastic import state_specs
 from repro.launch import hlo_analysis, roofline as rl
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, make_production_mesh, peaks_for
 from repro.models import get_model
 from repro.models.config import ModelConfig
 from repro.models.sharding import (
@@ -209,10 +212,7 @@ def run_cell(
     donate = (0,) if kind == "train" else ((1,) if kind == "decode" else ())
 
     t0 = time.time()
-    # jax < 0.7 has no jax.set_mesh; entering the Mesh object is the
-    # legacy spelling of the same ambient-mesh context.
-    mesh_ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-    with mesh_ctx:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             fn, out_shardings=out_shardings, donate_argnums=donate
         ).lower(*args)
@@ -242,6 +242,7 @@ def run_cell(
         flops_per_device=summary.flops,
         bytes_per_device=summary.traffic_bytes,
         traffic=traffic,
+        device_kind=PRODUCTION_DEVICE_KIND,
     )
 
     per_dev_bytes = {
@@ -268,7 +269,8 @@ def run_cell(
                 "bytes_accessed": cost.get("bytes accessed", 0.0),
             },
             "memory": per_dev_bytes,
-            "fits_hbm16g": per_dev_bytes["peak_estimate"] < 16e9,
+            "fits_hbm16g": per_dev_bytes["peak_estimate"]
+            < peaks_for(PRODUCTION_DEVICE_KIND).hbm_bytes,
             "useful_flops_ratio": (
                 art["model_flops"] / (summary.flops * n_dev)
                 if summary.flops

@@ -1,4 +1,4 @@
-"""Production mesh construction.
+"""Production mesh construction and the per-chip peaks table.
 
 A FUNCTION (not a module-level constant) so importing this module never
 touches jax device state — the dry-run sets
@@ -8,24 +8,57 @@ init, and nothing here may run earlier.
 
 from __future__ import annotations
 
+from dataclasses import dataclass
+from typing import Dict
+
 import jax
+
+
+# The chip the production mesh is made of; the dry run's roofline reads
+# its peaks.
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    # jax < 0.7 has neither sharding.AxisType nor the axis_types kwarg;
-    # Auto is the default there, so plain make_mesh is equivalent.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(
-        shape, axes, axis_types=(axis_type.Auto,) * len(axes)
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
     )
 
 
-# TPU v5e hardware constants (per chip), used by the roofline analysis.
-PEAK_BF16_FLOPS = 197e12  # FLOP/s
-HBM_BW = 819e9  # B/s
-ICI_BW = 50e9  # B/s per link (we charge aggregate per-chip traffic at 1 link)
-DCN_BW = 25e9  # B/s per host for the cross-pod axis (documented assumption)
+@dataclass(frozen=True)
+class ChipPeaks:
+    bf16_flops: float  # FLOP/s
+    hbm_bytes: float  # B
+    hbm_bw: float  # B/s
+    ici_bw: float  # B/s per link (aggregate per-chip traffic is charged at 1 link)
+    dcn_bw: float  # B/s per host for the cross-pod axis
+
+
+# Per-chip peaks keyed by ``jax.Device.device_kind``.  Source: Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM
+# at 819 GB/s, 1,600 Gbit/s of interchip interconnect over 4 links (50 GB/s
+# each).  The page publishes no data-center-network figure: ``dcn_bw`` is
+# the dry run's own assumption.
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        bf16_flops=197e12,
+        hbm_bytes=16e9,
+        hbm_bw=819e9,
+        ici_bw=50e9,
+        dcn_bw=25e9,
+    ),
+}
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """The published peaks of ``device_kind``; a kind not in the table is
+    an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)})"
+        ) from None

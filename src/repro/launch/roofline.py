@@ -29,7 +29,7 @@ import json
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
 
-from .mesh import DCN_BW, HBM_BW, ICI_BW, PEAK_BF16_FLOPS
+from .mesh import peaks_for
 
 
 def collective_traffic(
@@ -77,15 +77,20 @@ def roofline_terms(
     flops_per_device: float,
     bytes_per_device: float,
     traffic: Dict[str, Any],
+    device_kind: str,
 ) -> Dict[str, Any]:
-    t_compute = flops_per_device / PEAK_BF16_FLOPS
-    t_memory = bytes_per_device / HBM_BW
-    t_coll = traffic["ici"] / ICI_BW + traffic["dcn"] / DCN_BW
+    """Roofline terms against the peaks of ``device_kind``, the chip the
+    program was compiled for."""
+    peaks = peaks_for(device_kind)
+    t_compute = flops_per_device / peaks.bf16_flops
+    t_memory = bytes_per_device / peaks.hbm_bw
+    t_coll = traffic["ici"] / peaks.ici_bw + traffic["dcn"] / peaks.dcn_bw
     terms = {"compute_s": t_compute, "memory_s": t_memory, "collective_s": t_coll}
     dominant = max(terms, key=terms.get)
     bound = max(terms.values())
     return {
         **terms,
+        "device_kind": device_kind,
         "dominant": dominant,
         "roofline_fraction": (t_compute / bound) if bound > 0 else 1.0,
         "collective_bytes_ici": traffic["ici"],

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 from repro.serve import Engine
 
@@ -26,6 +27,7 @@ def main() -> None:
     ap.add_argument("--gen", type=int, default=24)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     if args.smoke:
@@ -50,7 +52,9 @@ def main() -> None:
     dt = time.time() - t0
     print(f"arch={cfg.arch_id} batch={args.batch} prompt={args.prompt_len} "
           f"generated={out.steps} tokens/request")
-    print(f"wall {dt:.2f}s -> {args.batch * out.steps / dt:.1f} tok/s (CPU, incl. compile)")
+    dev = jax.devices()[0]
+    print(f"wall {dt:.2f}s -> {args.batch * out.steps / dt:.1f} tok/s "
+          f"({dev.platform} {dev.device_kind} x{jax.device_count()}, incl. compile)")
     for i in range(min(args.batch, 2)):
         print(f"  request {i}: {out.tokens[i].tolist()}")
 

@@ -16,6 +16,7 @@ import json
 
 from repro.configs import get_config, get_smoke_config
 from repro.coord import ElasticConfig, ElasticTrainer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import OptConfig
 from repro.train.data import DataConfig
 
@@ -33,6 +34,7 @@ def main() -> None:
     ap.add_argument("--scale-at", action="append", default=[], metavar="STEP=pods")
     ap.add_argument("--fail-at", action="append", default=[], metavar="STEP=dead:replacement")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     cfg = cfg.replace(dtype="float32" if args.smoke else cfg.dtype)

@@ -140,6 +140,7 @@ class TestCollectives:
             flops_per_device=197e12,  # exactly 1s of compute
             bytes_per_device=819e9 / 2,  # 0.5s memory
             traffic={"ici": 0, "dcn": 0, "by_op": {}, "n": 0},
+            device_kind="TPU v5 lite",
         )
         assert r["dominant"] == "compute_s"
         assert r["roofline_fraction"] == pytest.approx(1.0)
@@ -147,6 +148,16 @@ class TestCollectives:
             flops_per_device=197e12 / 10,
             bytes_per_device=819e9,
             traffic={"ici": 0, "dcn": 0, "by_op": {}, "n": 0},
+            device_kind="TPU v5 lite",
         )
         assert r2["dominant"] == "memory_s"
         assert r2["roofline_fraction"] == pytest.approx(0.1)
+
+    def test_roofline_terms_unknown_device_kind(self):
+        with pytest.raises(ValueError, match="no published peaks"):
+            roofline_terms(
+                flops_per_device=1.0,
+                bytes_per_device=1.0,
+                traffic={"ici": 0, "dcn": 0, "by_op": {}, "n": 0},
+                device_kind="cpu",
+            )
