@@ -2,9 +2,7 @@
 
   PYTHONPATH=src python -m benchmarks.run [--full]
 
---full restores the paper's 1:1 experiment durations (10x slower).  The
-roofline section reads cached dry-run artifacts (artifacts/dryrun) —
-regenerate them with ``python -m repro.launch.dryrun_all``.
+--full restores the paper's 1:1 experiment durations (10x slower).
 """
 
 from __future__ import annotations
@@ -26,7 +24,6 @@ def main() -> None:
     from benchmarks import (
         bench_ablation,
         bench_batching,
-        bench_elastic,
         bench_fast_paxos,
         bench_horizontal,
         bench_leader_failure,
@@ -34,7 +31,6 @@ def main() -> None:
         bench_nemesis,
         bench_proc,
         bench_reconfiguration,
-        bench_roofline,
         bench_sharding,
         bench_thriftiness,
         bench_wire,
@@ -54,8 +50,6 @@ def main() -> None:
         ("sharded log plane", bench_sharding.main),
         ("sec8 reconfiguration under fire", bench_nemesis.main),
         ("process plane (one OS process per node)", lambda: bench_proc.main(fast=True)),
-        ("elastic control plane", bench_elastic.main),
-        ("roofline table", bench_roofline.main),
     ]
     for name, fn in suites:
         t0 = time.time()

@@ -27,9 +27,11 @@ file assumes simulated time.
 from __future__ import annotations
 
 import itertools
+import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from repro import obs
 from repro.core import messages as m
 from repro.core.acceptor import Acceptor
 from repro.core.deploy import ClusterSpec, Deployment
@@ -157,6 +159,8 @@ class ClusterController:
             num_shards=self.num_shards,
         )
         self.sim = Simulator(seed=seed, net=net)
+        # Shared with the trainer that drives this controller.
+        self.tracer = obs.NULL
         self.dep: Deployment = self.spec.instantiate(self.sim)
         self.pods: Dict[str, PodInfo] = {}
         self._acc_seq = itertools.count()
@@ -305,64 +309,73 @@ class ClusterController:
 
     # -- ledger operations --------------------------------------------------
     def commit(self, op: Any, timeout: float = 1.0) -> int:
-        """Propose ``op`` and run the sim until it is chosen; returns slot."""
+        """Propose ``op`` and run the sim until it is chosen; returns slot.
+        Counts the 1 ms ticks it took as ``ledger.ticks``."""
         cmd = m.Command(cmd_id=("ctrl", next(self._cmd_seq)), op=op)
         from repro.core.client import shard_of_command
 
-        leader = self.dep.shard_leader(shard_of_command(cmd.cmd_id, self.num_shards))
-        before = set(leader.chosen_values)
-        leader.on_message("ctrl", m.ClientRequest(command=cmd))
-        deadline = self.sim.now + timeout
-        while self.sim.now < deadline:
-            self.sim.run_for(0.001)
-            for slot, v in leader.chosen_values.items():
-                if slot not in before and isinstance(v, m.Command) and v.cmd_id == cmd.cmd_id:
-                    return slot
+        with self.tracer.span("ledger.commit"):
+            leader = self.dep.shard_leader(shard_of_command(cmd.cmd_id, self.num_shards))
+            before = set(leader.chosen_values)
+            leader.on_message("ctrl", m.ClientRequest(command=cmd))
+            deadline = self.sim.now + timeout
+            ticks = 0
+            while self.sim.now < deadline:
+                self.sim.run_for(0.001)
+                ticks += 1
+                for slot, v in leader.chosen_values.items():
+                    if slot not in before and isinstance(v, m.Command) and v.cmd_id == cmd.cmd_id:
+                        self.tracer.count("ledger.ticks", ticks)
+                        return slot
         raise TimeoutError(f"ledger commit of {op!r} timed out")
 
     def reconfigure(self, new_pods: Sequence[str]) -> Dict[str, float]:
         """Membership change: one Matchmaker reconfiguration + one ledger
-        entry.  Returns timing telemetry (the paper's 'few ms' claim)."""
-        for p in new_pods:
-            self.add_pod(p)
-        t0 = self.sim.now
-        n_reconfigs_before = len(self.dep.oracle.reconfig_durations)
-        # Every shard swaps onto the new pods' acceptor slices — one
-        # membership change is num_shards independent consensus
-        # reconfigurations against the shared matchmaker set.  A shard
-        # caught without a stable leader (mid-takeover, leader crashed)
-        # must not be silently left on the old membership: promote its
-        # live proposer straight onto the new configuration instead
-        # (takeover = full Phase 1 against the new acceptor set).
-        n_started = 0
-        skipped = []
-        for s in range(self.num_shards):
-            leader = self.dep.shard_leader(s)
-            cfg = self._config_for(new_pods, shard=s)
-            if leader.is_leader and leader.round is not None:
-                leader.reconfigure(cfg)
-                n_started += 1
-            elif not leader.failed:
-                leader.become_leader(cfg)
-                n_started += 1
-            else:
-                skipped.append(s)  # every proposer of the shard is down
-        # The new configuration is active right after the Matchmaking
-        # phase (Optimization 2 keeps commands flowing meanwhile).
-        deadline = self.sim.now + 1.0
-        while (
-            len(self.dep.oracle.reconfig_durations) < n_reconfigs_before + n_started
-            and self.sim.now < deadline
-        ):
-            self.sim.run_for(0.001)
-        t_active = self.sim.now
-        self.epoch += 1
-        self.epoch_pods = tuple(new_pods)
-        self.commit(ReconfigCommand(epoch=self.epoch, pods=self.epoch_pods))
+        entry.  Returns timing telemetry (the paper's 'few ms' claim): the
+        simulated ``activation_ms`` and the host's ``wall_ms``."""
+        wall0 = time.perf_counter()
+        with self.tracer.span("ledger.reconfigure"):
+            for p in new_pods:
+                self.add_pod(p)
+            t0 = self.sim.now
+            n_reconfigs_before = len(self.dep.oracle.reconfig_durations)
+            # Every shard swaps onto the new pods' acceptor slices — one
+            # membership change is num_shards independent consensus
+            # reconfigurations against the shared matchmaker set.  A shard
+            # caught without a stable leader (mid-takeover, leader crashed)
+            # must not be silently left on the old membership: promote its
+            # live proposer straight onto the new configuration instead
+            # (takeover = full Phase 1 against the new acceptor set).
+            n_started = 0
+            skipped = []
+            for s in range(self.num_shards):
+                leader = self.dep.shard_leader(s)
+                cfg = self._config_for(new_pods, shard=s)
+                if leader.is_leader and leader.round is not None:
+                    leader.reconfigure(cfg)
+                    n_started += 1
+                elif not leader.failed:
+                    leader.become_leader(cfg)
+                    n_started += 1
+                else:
+                    skipped.append(s)  # every proposer of the shard is down
+            # The new configuration is active right after the Matchmaking
+            # phase (Optimization 2 keeps commands flowing meanwhile).
+            deadline = self.sim.now + 1.0
+            while (
+                len(self.dep.oracle.reconfig_durations) < n_reconfigs_before + n_started
+                and self.sim.now < deadline
+            ):
+                self.sim.run_for(0.001)
+            t_active = self.sim.now
+            self.epoch += 1
+            self.epoch_pods = tuple(new_pods)
+            self.commit(ReconfigCommand(epoch=self.epoch, pods=self.epoch_pods))
         return {
             "reconfig_started": t0,
             "config_active": t_active,
             "activation_ms": (t_active - t0) * 1e3,
+            "wall_ms": (time.perf_counter() - wall0) * 1e3,
             "shards_reconfigured": float(n_started),
             "shards_skipped": float(len(skipped)),
         }

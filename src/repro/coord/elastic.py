@@ -28,6 +28,12 @@ training):
 On this container "pods" are disjoint groups of XLA host devices; the
 same code runs unchanged on real multi-pod slices where each group is a
 pod's chips.
+
+Tracing: the trainer and its controller share one tracer (``repro.obs``;
+``obs.NULL`` until one is attached as ``trainer.tracer``).  Each step is
+a ``step`` span holding ``batch``, ``dispatch`` (the jitted call),
+``loss_sync`` (the wait for the loss), ``ledger.advance``,
+``ledger.commit``, ``checkpoint`` and ``remesh``.
 """
 
 from __future__ import annotations
@@ -44,6 +50,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.coord.control_plane import ClusterController
 from repro.core.proposer import Options
 from repro.models.config import ModelConfig
@@ -169,15 +176,26 @@ class ElasticTrainer:
         assert need <= len(devs), f"need {need} devices, have {len(devs)}"
         return np.array(devs[:need]).reshape(len(pods), per)
 
+    @property
+    def tracer(self):
+        """The tracer this trainer and its controller share (``obs.NULL``
+        until one is attached)."""
+        return self.controller.tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        self.controller.tracer = tracer
+
     def _remesh(self, pods: List[str]) -> None:
-        groups = self._device_groups(pods)
-        self.mesh = Mesh(groups, ("pod", "data"))
-        maxes = axis_sizes(self.mesh)
-        specs = state_specs(self.cfg, self.state, maxes)
-        shardings = named(self.mesh, specs)
-        self.state = jax.device_put(self.state, shardings)
-        self._state_shardings = shardings
-        self.pods = list(pods)
+        with self.tracer.span("remesh"):
+            groups = self._device_groups(pods)
+            self.mesh = Mesh(groups, ("pod", "data"))
+            maxes = axis_sizes(self.mesh)
+            specs = state_specs(self.cfg, self.state, maxes)
+            shardings = named(self.mesh, specs)
+            self.state = jax.device_put(self.state, shardings)
+            self._state_shardings = shardings
+            self.pods = list(pods)
         self.events.append(
             {"t": "remesh", "step": self.step, "pods": list(pods), "devices": int(groups.size)}
         )
@@ -203,22 +221,28 @@ class ElasticTrainer:
 
     # ------------------------------------------------------------------
     def run(self, n_steps: int) -> None:
+        tr = self.tracer
         for _ in range(n_steps):
-            batch = self._batch()
-            self.state, metrics = self._step_jit()(self.state, batch)
-            self.losses.append(float(metrics["loss"]))
-            self.step += 1
-            # advance the control plane "concurrently"
-            self.controller.sim.run_for(0.002)
-            if self.step % self.ecfg.commit_every == 0:
-                self.controller.commit_step(self.step)
-            if self.step % self.ecfg.checkpoint_every == 0:
-                self.save_checkpoint()
-            # react to membership decided by the ledger
-            epoch, pods = self.controller.membership()
-            if epoch != self.epoch and pods:
-                self.epoch = epoch
-                self._remesh(list(pods))
+            with tr.step(self.step):
+                with tr.span("batch"):
+                    batch = self._batch()
+                with tr.span("dispatch"):
+                    self.state, metrics = self._step_jit()(self.state, batch)
+                with tr.span("loss_sync"):
+                    self.losses.append(float(metrics["loss"]))
+                self.step += 1
+                # advance the control plane "concurrently"
+                with tr.span("ledger.advance"):
+                    self.controller.sim.run_for(0.002)
+                if self.step % self.ecfg.commit_every == 0:
+                    self.controller.commit_step(self.step)
+                if self.step % self.ecfg.checkpoint_every == 0:
+                    self.save_checkpoint()
+                # react to membership decided by the ledger
+                epoch, pods = self.controller.membership()
+                if epoch != self.epoch and pods:
+                    self.epoch = epoch
+                    self._remesh(list(pods))
 
     # ------------------------------------------------------------------
     def scale_to(self, pods: Sequence[str]) -> Dict[str, float]:
@@ -237,16 +261,17 @@ class ElasticTrainer:
     # ------------------------------------------------------------------
     def save_checkpoint(self) -> None:
         t0 = time.perf_counter()
-        man = checkpoint.save(
-            self.ecfg.checkpoint_dir,
-            self.step,
-            self.state,
-            meta={"arch": self.cfg.arch_id, "epoch": self.epoch},
-        )
-        digest = hashlib.sha256(
-            json.dumps(man["files"], sort_keys=True).encode()
-        ).hexdigest()[:16]
-        self.controller.commit_checkpoint(self.step, digest)
+        with self.tracer.span("checkpoint"):
+            man = checkpoint.save(
+                self.ecfg.checkpoint_dir,
+                self.step,
+                self.state,
+                meta={"arch": self.cfg.arch_id, "epoch": self.epoch},
+            )
+            digest = hashlib.sha256(
+                json.dumps(man["files"], sort_keys=True).encode()
+            ).hexdigest()[:16]
+            self.controller.commit_checkpoint(self.step, digest)
         self.events.append(
             {"t": "checkpoint", "step": self.step, "seconds": time.perf_counter() - t0}
         )

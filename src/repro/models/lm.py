@@ -126,21 +126,23 @@ class LM:
     # ------------------------------------------------------------------
     def _attn_block_apply(self, p, x: Array, is_local: Any, positions=None):
         cfg = self.cfg
-        h = attn_apply(
-            cfg, p["attn"], rms_norm(x, p["ln1"]), is_local=is_local, positions=positions
-        )
-        if cfg.post_norm:
-            h = rms_norm(h, p["ln1_post"])
-        x = x + h
-        h2_in = rms_norm(x, p["ln2"])
-        aux = {}
-        if "moe" in p:
-            h2, aux = moe_apply(cfg, p["moe"], h2_in)
-        else:
-            h2 = mlp_apply(cfg, p["mlp"], h2_in)
-        if cfg.post_norm:
-            h2 = rms_norm(h2, p["ln2_post"])
-        return x + h2, aux
+        with jax.named_scope("attn"):
+            h = attn_apply(
+                cfg, p["attn"], rms_norm(x, p["ln1"]), is_local=is_local, positions=positions
+            )
+            if cfg.post_norm:
+                h = rms_norm(h, p["ln1_post"])
+            x = x + h
+        with jax.named_scope("mlp"):
+            h2_in = rms_norm(x, p["ln2"])
+            aux = {}
+            if "moe" in p:
+                h2, aux = moe_apply(cfg, p["moe"], h2_in)
+            else:
+                h2 = mlp_apply(cfg, p["mlp"], h2_in)
+            if cfg.post_norm:
+                h2 = rms_norm(h2, p["ln2_post"])
+            return x + h2, aux
 
     # ------------------------------------------------------------------
     # Forward (train / prefill): returns final hidden states + aux
@@ -149,16 +151,18 @@ class LM:
         self, params, tokens: Array, *, remat: bool = True
     ) -> Tuple[Array, Dict[str, Array]]:
         cfg = self.cfg
-        x = params["embed"][tokens].astype(_dtype(cfg))
-        if cfg.emb_scale_by_sqrt_dim:
-            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens].astype(_dtype(cfg))
+            if cfg.emb_scale_by_sqrt_dim:
+                x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
 
         if cfg.family in ("ssm", "hybrid"):
             x = self._ssm_stack(params, x, remat=remat)
             aux = {}
         else:
             x, aux = self._attn_stack(params, x, remat=remat)
-        return rms_norm(x, _cast_block(params["final_norm"], x.dtype)), aux
+        with jax.named_scope("head"):
+            return rms_norm(x, _cast_block(params["final_norm"], x.dtype)), aux
 
     def _attn_stack(self, params, x: Array, *, remat: bool):
         cfg = self.cfg
@@ -173,7 +177,8 @@ class LM:
 
         if remat:
             body = jax.checkpoint(body, prevent_cse=False)
-        x, auxs = jax.lax.scan(body, x, (params["blocks"], flags))
+        with jax.named_scope("layers"):
+            x, auxs = jax.lax.scan(body, x, (params["blocks"], flags))
         aux = {k: jnp.mean(v) for k, v in auxs.items()} if auxs else {}
         return x, aux
 
@@ -197,17 +202,19 @@ class LM:
 
             if shared is not None:
                 def with_attn(x):
-                    y, _ = self._attn_block_apply(
-                        _cast_block(shared, x.dtype), x, is_local=False
-                    )
-                    return y.astype(x.dtype)
+                    with jax.named_scope("shared_block"):
+                        y, _ = self._attn_block_apply(
+                            _cast_block(shared, x.dtype), x, is_local=False
+                        )
+                        return y.astype(x.dtype)
 
                 x = jax.lax.cond(flag, with_attn, lambda x: x, x)
             return x, None
 
         if remat:
             body = jax.checkpoint(body, prevent_cse=False)
-        x, _ = jax.lax.scan(body, x, (params["blocks"], flags))
+        with jax.named_scope("layers"):
+            x, _ = jax.lax.scan(body, x, (params["blocks"], flags))
         return x
 
     # ------------------------------------------------------------------
@@ -323,8 +330,9 @@ class LM:
         w = params.get("unembed")
         if w is None:
             w = params["embed"].T
-        out = jnp.einsum("bsd,dv->bsv", hidden, w).astype(jnp.float32)
-        return softcap(out, cfg.final_logit_softcap)
+        with jax.named_scope("head"):
+            out = jnp.einsum("bsd,dv->bsv", hidden, w).astype(jnp.float32)
+            return softcap(out, cfg.final_logit_softcap)
 
     def apply(self, params, tokens: Array, *, remat: bool = False) -> Array:
         hidden, _ = self.hidden_states(params, tokens, remat=remat)

@@ -75,6 +75,7 @@ def _segsum(a: Array) -> Array:
     return jnp.where(i >= j, diff, -jnp.inf)
 
 
+@jax.named_scope("ssd")
 def ssd_chunked(
     x: Array,  # (B, S, nh, hd)  (already multiplied by dt)
     a: Array,  # (B, S, nh)      log-decay = dt * A  (negative)
@@ -141,6 +142,7 @@ def _conv1d(xBC: Array, w: Array, b: Array) -> Array:
     return out + b[None, None, :]
 
 
+@jax.named_scope("mamba")
 def mamba_apply(
     cfg: ModelConfig,
     p,

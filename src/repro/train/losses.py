@@ -20,6 +20,7 @@ from repro.models.layers import softcap
 Array = jax.Array
 
 
+@jax.named_scope("head")
 def chunked_xent(
     cfg: ModelConfig,
     params: Any,

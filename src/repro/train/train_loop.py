@@ -5,6 +5,13 @@ the multi-pod dry-run) lowers.  Parameters are fp32 masters; the forward
 runs in the model dtype (bf16 on TPU).  Per-layer remat is on inside the
 model's scan.  ``TrainState`` is a plain pytree so checkpointing and
 sharding rules apply uniformly.
+
+Device operations carry fixed ``jax.named_scope`` names in their
+``op_name`` metadata, so a profile can say which layer spent the step:
+``embed``, ``layers`` (the layer scan), ``mamba`` and ``ssd`` within it,
+``shared_block`` with ``attn`` and ``mlp`` (zamba2's shared block),
+``attn`` and ``mlp`` in attention layers, ``head`` (final norm, logits,
+loss) and ``optimizer``.
 """
 
 from __future__ import annotations
@@ -136,9 +143,10 @@ def make_train_step(
             loss = loss_sum / microbatches
             metrics = {k: jnp.mean(v) for k, v in metricss.items()}
 
-        new_params, new_opt, opt_metrics = opt.update(
-            ocfg, state.params, grads, state.opt
-        )
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, opt_metrics = opt.update(
+                ocfg, state.params, grads, state.opt
+            )
         metrics = {**metrics, **opt_metrics, "loss": loss}
         return TrainState(new_params, new_opt, state.step + 1), metrics
 
