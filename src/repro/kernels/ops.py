@@ -4,17 +4,22 @@ Each op accepts ``use_pallas`` / ``interpret`` switches.  ``interpret``
 defaults to the backend (kernels/backend.py): on a TPU the Pallas path
 compiles natively, elsewhere it runs in the interpreter (tests).
 ``use_pallas=False`` takes the jnp reference instead.
+
+``fused_causal_attention`` is JAX's own splash attention (Pallas TPU
+forward, dQ and dKV kernels), the path ``attention(attn_impl="auto")``
+takes for causal multi-head attention on one TPU chip.
 """
 
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from . import ref
+from .backend import resolve_interpret
 from .decode_attention import decode_attention_bkh
 from .flash_attention import flash_attention_bhsd
 from .ssd_scan import ssd_intra_chunk
@@ -71,6 +76,64 @@ def flash_attention(
             qt, kt, vt, scale=scale, causal=causal, window=window, softcap=softcap
         )
     return out.transpose(0, 2, 1, 3)
+
+
+# --------------------------------------------------------------------------
+# Fused causal attention (splash), forward and backward
+# --------------------------------------------------------------------------
+# The splash kernels' blocks, measured at the zamba2 cell's shapes (S 4096,
+# hd 128) on a TPU v5e (PERF.md); each is cut to a shorter sequence.
+SPLASH_BLOCKS = dict(
+    block_q=512,
+    block_kv=512,
+    block_kv_compute=512,
+    block_q_dkv=512,
+    block_kv_dkv=512,
+    block_kv_dkv_compute=512,
+    block_q_dq=512,
+    block_kv_dq=512,
+)
+
+
+def fused_blocks(seq: int) -> Optional[dict]:
+    """The splash blocks for a sequence, or None where it is not a
+    multiple of each of them."""
+    blocks = {k: min(seq, b) for k, b in SPLASH_BLOCKS.items()}
+    return blocks if all(seq % b == 0 for b in blocks.values()) else None
+
+
+@lru_cache(maxsize=None)
+def _causal_splash(seq: int, heads: int, blocks: tuple, interpret: bool):
+    from jax.experimental.pallas.ops.tpu import splash_attention as sa
+
+    mask = sa.MultiHeadMask([sa.CausalMask((seq, seq))] * heads)
+    sizes = sa.BlockSizes(**dict(blocks))
+    # The mask's block tables are constants of every program that calls
+    # the kernel: make them concrete even when first asked for mid-trace.
+    with jax.ensure_compile_time_eval():
+        return sa.make_splash_mha_single_device(
+            mask, block_sizes=sizes, interpret=interpret
+        )
+
+
+def fused_causal_attention(
+    q: jax.Array,  # (B, S, H, hd)
+    k: jax.Array,  # (B, S, H, hd)
+    v: jax.Array,
+    *,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Exact causal softmax attention, scale ``hd ** -0.5``, with float32
+    logits, max and sum inside the kernels; bf16 in and out.  Key blocks
+    above the diagonal are skipped, and the backward recomputes the
+    scores block by block instead of reading them from HBM."""
+    B, S, H, hd = q.shape
+    blocks = tuple(fused_blocks(S).items())
+    kernel = _causal_splash(S, H, blocks, resolve_interpret(interpret))
+    # The kernels take no scale: fold it into q in float32, one rounding.
+    qs = (q.astype(jnp.float32) * hd ** -0.5).astype(q.dtype)
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (qs, k, v))
+    return jax.vmap(kernel)(qt, kt, vt).transpose(0, 2, 1, 3)
 
 
 @partial(

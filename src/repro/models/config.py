@@ -58,7 +58,9 @@ class ModelConfig:
     tie_embeddings: bool = True
     emb_scale_by_sqrt_dim: bool = False  # gemma
     dtype: str = "bfloat16"
-    # attention impl: "auto" picks chunked for long seq, naive for short.
+    # attention impl: "auto" takes the fused splash kernels for causal MHA
+    # on one TPU chip, else chunked for long seq, naive for short
+    # (models/layers.py _attention_path); "chunked" | "naive" | "pallas".
     attn_impl: str = "auto"
     # Activation sharding policy: "none" (single-device tests) | "tp" |
     # "fsdp" — see models/sharding.py.  Set by the launcher/dry-run.

@@ -12,6 +12,10 @@ implementations:
   * ``decode``  — single-token attention against a KV cache, with windowed
     reads for local (sliding-window) layers.
 
+``attention`` picks one (``_attention_path``); under ``attn_impl="auto"``
+causal multi-head attention on one TPU chip takes the fused splash
+kernels (kernels/ops.py ``fused_causal_attention``) instead.
+
 Masks are built from ``broadcasted_iota`` — never materialized constants —
 so a 32k x 32k causal mask costs nothing at trace time.
 """
@@ -252,15 +256,82 @@ def attention_fsdp_seqshard(
     )(q, k, v, jnp.asarray(is_local))
 
 
-def attention(q, k, v, *, cfg: ModelConfig, **kw) -> Array:
-    impl = cfg.attn_impl
+def _static_bool(x: Any) -> Optional[bool]:
+    """``x`` as a Python bool, or None where it is only known at run time."""
+    try:
+        return bool(x)
+    except jax.errors.ConcretizationTypeError:
+        return None
+
+
+def _attention_path(
+    q_shape: Tuple[int, ...],  # (B, Sq, H, hd)
+    k_shape: Tuple[int, ...],  # (B, Sk, K, hd)
+    cfg: ModelConfig,
+    *,
+    causal: bool,
+    is_local: Optional[bool],  # None: decided at run time
+    q_offset: Any,
+    platform: str,
+    devices: int,
+) -> str:
+    """Which implementation ``attention`` runs: ``fsdp`` (sequence-sharded
+    chunked), ``fused`` (kernels/ops.py's splash kernels), ``chunked``,
+    ``naive`` or ``pallas``.  ``attn_impl="auto"`` takes ``fused`` for
+    causal multi-head self-attention with no window or softcap on one TPU
+    chip (a Mosaic kernel cannot be partitioned by GSPMD) at a sequence
+    that is a multiple of the kernels' blocks and head_dim a multiple of
+    128; every other call keeps the chunked / naive choice."""
+    from repro.kernels.ops import fused_blocks
+
     if cfg.sharding_policy == "fsdp":
+        return "fsdp"
+    if cfg.attn_impl != "auto":
+        return cfg.attn_impl
+    _, Sq, H, hd = q_shape
+    Sk, K = k_shape[1], k_shape[2]
+    if (
+        platform == "tpu"
+        and devices == 1
+        and cfg.sharding_policy == "none"
+        and causal
+        and (cfg.sliding_window is None or is_local is False)
+        and cfg.attn_logit_softcap is None
+        and K == H
+        and Sq == Sk
+        and isinstance(q_offset, int)
+        and q_offset == 0
+        and Sq % 128 == 0
+        and fused_blocks(Sq) is not None
+        and hd % 128 == 0
+    ):
+        return "fused"
+    return "chunked" if Sq > 2 * cfg.attn_q_chunk else "naive"
+
+
+def attention(q, k, v, *, cfg: ModelConfig, **kw) -> Array:
+    from repro import obs
+
+    path = _attention_path(
+        q.shape,
+        k.shape,
+        cfg,
+        causal=kw.get("causal", True),
+        is_local=_static_bool(kw.get("is_local", False)),
+        q_offset=kw.get("q_offset", 0),
+        platform=jax.default_backend(),
+        devices=jax.device_count(),
+    )
+    obs.count_event(f"attention.{path}")
+    if path == "fsdp":
         return attention_fsdp_seqshard(q, k, v, cfg=cfg, **kw)
-    if impl == "auto":
-        impl = "chunked" if q.shape[1] > 2 * cfg.attn_q_chunk else "naive"
-    if impl == "chunked":
+    if path == "fused":
+        from repro.kernels import ops as kops
+
+        return kops.fused_causal_attention(q, k, v)
+    if path == "chunked":
         return attention_chunked(q, k, v, cfg=cfg, **kw)
-    if impl == "pallas":
+    if path == "pallas":
         # TPU path: is_local must be static here (on real hardware each
         # local/global layer group lowers its own kernel instance).
         from repro.kernels import ops as kops

@@ -9,7 +9,10 @@ per step.  While a recording tracer is in a step, every backend compile
 from the persistent cache) counts as ``compiles`` in that step.  Each step
 also records the process's CPU seconds and involuntary context switches
 over it (``resource.getrusage``), so a long step shows whether the host
-was descheduled.
+was descheduled.  Code with no tracer at hand counts through
+``count_event(name)``, a ``jax.monitoring`` event that the recording
+tracer in a step counts as ``name``: the model's ``attention`` counts the
+path each traced call takes (``attention.fused``, ``attention.chunked``).
 
 With ``annotate=True`` each span is also a
 ``jax.profiler.TraceAnnotation("repro.<name>")`` and each step a
@@ -32,6 +35,7 @@ import jax
 PREFIX = "repro."
 STEP = "train"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+COUNT_EVENT = "/repro/count/"
 
 _active: Optional["Tracer"] = None  # the recording tracer inside a step
 _listening = False
@@ -42,11 +46,23 @@ def _on_duration(event: str, secs: float, **_: Any) -> None:
         _active.count("compiles")
 
 
+def _on_event(event: str, **_: Any) -> None:
+    if event.startswith(COUNT_EVENT) and _active is not None:
+        _active.count(event[len(COUNT_EVENT) :])
+
+
 def _listen() -> None:
     global _listening
     if not _listening:
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_event)
         _listening = True
+
+
+def count_event(name: str) -> None:
+    """Count ``name`` in the current step of the recording tracer, if one
+    is in a step; nothing else listens."""
+    jax.monitoring.record_event(COUNT_EVENT + name)
 
 
 def _rusage() -> Tuple[float, int]:

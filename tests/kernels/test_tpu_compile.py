@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import ops
 from repro.kernels.decode_attention import decode_attention_bkh
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.ssd_scan import ssd_intra_chunk
@@ -87,3 +88,15 @@ def test_ssd_intra_chunk_compiles(one_chip):
         ((B, nh, nC, Q, N), jnp.bfloat16),
     )
     assert "tpu_custom_call" in text
+
+
+def test_fused_attention_grad_compiles(one_chip):
+    B, S, H, hd = 4, 4096, 32, 128  # the zamba2-train cell's shared block
+
+    def loss(q, k, v):
+        out = ops.fused_causal_attention(q, k, v, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    qkv = ((B, S, H, hd), jnp.bfloat16)
+    text = _compile_text(jax.grad(loss, argnums=(0, 1, 2)), one_chip, qkv, qkv, qkv)
+    assert text.count("tpu_custom_call") == 3  # forward, dQ, dKV
